@@ -233,17 +233,20 @@ def stream_entry_counters(proj: Projected, grid: TileGrid,
     n_listed = jnp.sum(valid)
     ctu_pairs = jnp.sum(sub_hits)
 
+    # PRs per hit mini-tile, doubled to stay integer: a float32 sum past
+    # 2^24 rounds in whatever order the compiler reduces, which differs
+    # between the tile-sharded and the single-device program.
     spiky = classify_spiky(proj.axis_ratio, spiky_threshold)
     if mode == SamplingMode.UNIFORM_DENSE:
-        prs_per_minitile = jnp.full(proj.depth.shape, 1.0)
+        prs2_per_minitile = jnp.full(proj.depth.shape, 2)
     elif mode == SamplingMode.UNIFORM_SPARSE:
-        prs_per_minitile = jnp.full(proj.depth.shape, 0.5)
+        prs2_per_minitile = jnp.full(proj.depth.shape, 1)
     elif mode == SamplingMode.SMOOTH_FOCUSED:
-        prs_per_minitile = jnp.where(spiky, 0.5, 1.0)
+        prs2_per_minitile = jnp.where(spiky, 1, 2)
     else:  # SPIKY_FOCUSED
-        prs_per_minitile = jnp.where(spiky, 1.0, 0.5)
+        prs2_per_minitile = jnp.where(spiky, 2, 1)
     mpsub = grid.minitiles_per_subtile
-    ctu_prs = jnp.sum(sub_hits * prs_per_minitile[idx]) * mpsub
+    ctu_prs2 = jnp.sum(sub_hits * prs2_per_minitile[idx]) * mpsub
 
     return dict(
         n_gaussians=jnp.asarray(proj.depth.shape[0], jnp.float32),
@@ -252,7 +255,7 @@ def stream_entry_counters(proj: Projected, grid: TileGrid,
         # Without Stage 1 the CTU tests every sub-tile of every stream entry.
         ctu_pairs_no_stage1=(n_listed
                              * grid.subtiles_per_tile).astype(jnp.float32),
-        ctu_prs=ctu_prs.astype(jnp.float32),
+        ctu_prs=ctu_prs2.astype(jnp.float32) / 2,
         leader_tests_per_pair=leader_pixel_count(proj, grid, mode,
                                                  spiky_threshold),
         dup_tile=n_listed.astype(jnp.float32),

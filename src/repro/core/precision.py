@@ -12,16 +12,22 @@ point:
               preserved), THEN converted to FP8 for the quadratic unit
               (lines 2-7 of Alg. 1); accumulation in FP16.
 
-Quantization uses JAX's native float16 / float8_e4m3fn round-trip casts, so
-numerics match the hardware units' mantissa truncation.
+Quantization rounds a float32 value onto the fp16 / fp8 (float8_e4m3fn)
+grid explicitly: round to nearest even on the mantissa bits, the format's
+subnormal spacing below its smallest normal, and its overflow (inf for
+fp16; NaN for e4m3fn, which has no inf — the cast's own semantics). The
+result equals the `x.astype(fmt).astype(float32)` round trip bit for bit,
+but is written in integer and float32 ops only: XLA may drop a
+down-then-up convert pair when it fuses (it does under `vmap`), and Mosaic
+has no lowering for `lax.reduce_precision`. So the jnp path and the Pallas
+kernels quantize identically on every backend, batched or not.
 """
 from __future__ import annotations
 
 import dataclasses
 
 import jax.numpy as jnp
-
-FP8 = jnp.float8_e4m3fn
+from jax import lax
 
 
 @dataclasses.dataclass(frozen=True)
@@ -38,16 +44,16 @@ class PrecisionScheme:
     slack: float = 0.0
 
     def q_coord(self, x):
-        return _quant(x, self.coord)
+        return quantize(x, self.coord)
 
     def q_delta(self, x):
-        return _quant(x, self.delta)
+        return quantize(x, self.delta)
 
     def q_mul(self, x):
-        return _quant(x, self.mul)
+        return quantize(x, self.mul)
 
     def q_acc(self, x):
-        return _quant(x, self.acc)
+        return quantize(x, self.acc)
 
 
 FULL_FP32 = PrecisionScheme()
@@ -58,11 +64,37 @@ FULL_FP8 = PrecisionScheme("fp8", "fp8", "fp16", "fp16", slack=0.15)
 MIXED = PrecisionScheme("fp16", "fp8", "fp16", "fp16", slack=0.15)
 
 
-def _quant(x, kind: str):
+# (mantissa bits, smallest normal exponent, largest finite, overflow value)
+_FORMATS = {
+    "fp16": (10, -14, 65504.0, jnp.inf),
+    "fp8": (3, -6, 448.0, jnp.nan),         # float8_e4m3fn
+}
+
+
+def _round_mantissa(x, mantissa_bits: int):
+    """Round float32 `x` to `mantissa_bits` explicit mantissa bits, to
+    nearest with ties to even, on its bit pattern (exponent range kept;
+    inf/NaN pass through)."""
+    shift = 23 - mantissa_bits
+    bits = lax.bitcast_convert_type(x, jnp.int32)
+    odd = (bits >> shift) & 1
+    rounded = (bits + ((1 << (shift - 1)) - 1) + odd) & ~((1 << shift) - 1)
+    finite = (bits & 0x7F800000) != 0x7F800000
+    return jnp.where(finite, lax.bitcast_convert_type(rounded, jnp.float32),
+                     x)
+
+
+def quantize(x, kind: str):
+    """float32 `x` rounded onto the `kind` grid ("fp32" | "fp16" | "fp8"),
+    returned as float32 — bit-identical to the cast round trip."""
     if kind == "fp32":
         return x
-    if kind == "fp16":
-        return x.astype(jnp.float16).astype(jnp.float32)
-    if kind == "fp8":
-        return x.astype(FP8).astype(jnp.float32)
-    raise ValueError(kind)
+    if kind not in _FORMATS:
+        raise ValueError(kind)
+    mbits, emin, fmax, overflow = _FORMATS[kind]
+    ulp_sub = 2.0 ** (emin - mbits)          # subnormal spacing (exact)
+    y = jnp.where(jnp.abs(x) < 2.0 ** emin,
+                  jnp.round(x * (1.0 / ulp_sub)) * ulp_sub,
+                  _round_mantissa(x, mbits))
+    return jnp.where(jnp.abs(y) > fmax, jnp.where(x < 0, -overflow, overflow),
+                     y)

@@ -205,9 +205,8 @@ class ShardConfig:
     Requirements: the stream dataflow with the CAT method, a tile count
     divisible by tile_shards, an active mesh (`distributed.sharding.use_mesh`
     or `serving.RenderEngine(shard_tiles=...)`) whose resolved axis has size
-    tile_shards, and execution under `jax.jit` (shard_map with auto axes has
-    no eager path). Part of the plan hash, so the serving jit cache keys on
-    it like every other stage config.
+    tile_shards, and execution under `jax.jit`. Part of the plan hash, so
+    the serving jit cache keys on it like every other stage config.
     """
     tile_shards: int = 1
     axis: str = "tile"
@@ -697,13 +696,14 @@ class RenderPlan:
         render is bit-identical on images, entry_alive and every additive
         counter.
 
-        Frame x tile composition: every mesh axis other than the shard axis
-        is left `auto`, so a vmapped frame batch sharded over "data" keeps
-        its placement while tiles split over "model". shard_map with auto
-        axes has no eager path — runs must be under `jax.jit` (the serving
-        engine always is).
+        The shard_map is manual over every mesh axis: Mosaic-compiled
+        kernels (the Pallas CTU and blend) cannot be partitioned over an
+        auto axis. Frame x tile composition comes from
+        `render_batch_with_stats`, whose vmap maps the frame axis onto the
+        mesh's data axes (`spmd_axis_name`), so a frame batch sharded over
+        "data" keeps its placement while tiles split over "model". Runs
+        must be under `jax.jit` (the serving engine always is).
         """
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.distributed import sharding as dshard
 
@@ -730,8 +730,7 @@ class RenderPlan:
                 f"tile_shards={s}")
         if not isinstance(proj.depth, jax.core.Tracer):
             raise RuntimeError(
-                "tile-sharded rendering must run under jax.jit: shard_map "
-                "with auto mesh axes has no eager execution path (wrap the "
+                "tile-sharded rendering must run under jax.jit (wrap the "
                 "render in jax.jit, or use serving.RenderEngine which "
                 "always jits)")
 
@@ -741,7 +740,6 @@ class RenderPlan:
         valid_all = jnp.stack([ts.valid for ts in streams])
         t_origins = grid.tile_origins()                       # (T, 2) int
         tile_spec, pass_spec = P(axes), P(None, axes)
-        auto = frozenset(mesh.axis_names) - set(axes_tuple)
 
         def body(proj_s, t_orig, lists_s, valid_s):
             pass_rows, subs, minis = [], [], []
@@ -768,10 +766,10 @@ class RenderPlan:
                          sub_hits=pass_spec, mini_hits=pass_spec)
         if self.raster.fused:
             out_specs["kproc"] = pass_spec
-        shard_out = shard_map(
+        shard_out = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), tile_spec, pass_spec, pass_spec),
-            out_specs=out_specs, check_rep=False, auto=auto)(
+            out_specs=out_specs, check_vma=False)(
                 proj, t_origins, lists_all, valid_all)
 
         # The single gather: replicate the per-shard rows (ints and
@@ -933,8 +931,17 @@ class RenderPlan:
             raise ValueError(
                 f"camera resolution {(cameras.height, cameras.width)} != "
                 f"plan grid {(self.grid.height, self.grid.width)}")
+        spmd_axes = None
+        if self.shard.tile_shards > 1:
+            # The tile shard_map is manual over the data axes too: map the
+            # frame axis onto them here (see `_render_streams_sharded`).
+            from repro.distributed import sharding as dshard
+            mesh = dshard.active_mesh()
+            if mesh is not None:
+                spmd_axes = dshard.dp_axes(mesh) or None
         out, counters = jax.vmap(
-            lambda cam: self.render_with_stats(scene, cam))(cameras)
+            lambda cam: self.render_with_stats(scene, cam),
+            spmd_axis_name=spmd_axes)(cameras)
         enforce_overflow_policy(jnp.any(out.overflow), self.stream.overflow,
                                 k_max=self.stream.k_max,
                                 n_passes=self.n_passes)
@@ -1023,16 +1030,17 @@ class RenderPlan:
     # -- effective (termination-aware) counters -----------------------------
 
     def _prs_per_subtile(self, proj: Projected) -> jax.Array:
-        """(N,) PRs the CTU evaluates per hit sub-tile: 4 dense / 2 sparse
-        per Fig. 3(b), adaptive modes pick per Gaussian."""
+        """(N,) int PRs the CTU evaluates per hit sub-tile: 4 dense / 2
+        sparse per Fig. 3(b), adaptive modes pick per Gaussian. Integer so
+        the counter sums are exact in any reduction order."""
         spiky = classify_spiky(proj.axis_ratio, self.test.spiky_threshold)
         if self.test.mode == SamplingMode.UNIFORM_DENSE:
-            return jnp.full(spiky.shape, 4.0)
+            return jnp.full(spiky.shape, 4)
         if self.test.mode == SamplingMode.UNIFORM_SPARSE:
-            return jnp.full(spiky.shape, 2.0)
+            return jnp.full(spiky.shape, 2)
         if self.test.mode == SamplingMode.SMOOTH_FOCUSED:
-            return jnp.where(spiky, 2.0, 4.0)
-        return jnp.where(spiky, 4.0, 2.0)
+            return jnp.where(spiky, 2, 4)
+        return jnp.where(spiky, 4, 2)
 
     def _effective_counters_from_hits(self, proj: Projected, lists,
                                       sub_hits, mini_hits,

@@ -18,7 +18,15 @@ from __future__ import annotations
 import contextlib
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+
+
+def make_mesh(shape, axis_names) -> Mesh:
+    """`jax.make_mesh` with every axis `Auto`-typed: shardings propagate
+    through the compiler as they did before explicit axis types became the
+    default, so unannotated scatters such as `x.at[...].set` stay legal."""
+    return jax.make_mesh(shape, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def dp_axes(mesh: Mesh):

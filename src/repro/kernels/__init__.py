@@ -1,3 +1,14 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels of the render hot path (PRTU CTU and VRU blend).
+
+Where a kernel runs is decided here, from the platform alone: on the CPU
+backend (tests, CI) `pallas_call` interprets the kernel; on every other
+backend Mosaic compiles it. No caller chooses.
+"""
+from __future__ import annotations
+
+import jax
+
+
+def interpret_mode() -> bool:
+    """True iff Pallas kernels run in the interpreter: the CPU backend."""
+    return jax.default_backend() == "cpu"

@@ -31,8 +31,8 @@ from repro.kernels import ref as kref
 
 
 def cat_mask_pallas(proj: Projected, grid: TileGrid, mode: SamplingMode,
-                    prec: PrecisionScheme, spiky_threshold: float = 3.0,
-                    interpret: bool = True) -> jax.Array:
+                    prec: PrecisionScheme, spiky_threshold: float = 3.0
+                    ) -> jax.Array:
     """(num_minitiles, N) bool CAT mask via the PRTU kernel."""
     origins = grid.minitile_origins().astype(jnp.float32)
     m = float(grid.minitile - 1)
@@ -44,16 +44,14 @@ def cat_mask_pallas(proj: Projected, grid: TileGrid, mode: SamplingMode,
     mask = prtu.prtu_cat_mask(
         p_top, p_bot, proj.mean2d, proj.conic, lhs, spiky,
         mode=mode.value, coord_prec=prec.coord, delta_prec=prec.delta,
-        mul_prec=prec.mul, acc_prec=prec.acc, slack=prec.slack,
-        interpret=interpret)
+        mul_prec=prec.mul, acc_prec=prec.acc, slack=prec.slack)
     return mask != 0
 
 
 def hierarchical_test_pallas(proj: Projected, grid: TileGrid,
                              mode: SamplingMode, prec: PrecisionScheme,
-                             spiky_threshold: float = 3.0,
-                             interpret: bool = True) -> H.HierarchyOut:
-    cat = cat_mask_pallas(proj, grid, mode, prec, spiky_threshold, interpret)
+                             spiky_threshold: float = 3.0) -> H.HierarchyOut:
+    cat = cat_mask_pallas(proj, grid, mode, prec, spiky_threshold)
     return H.hierarchical_test(proj, grid, mode, prec, spiky_threshold,
                                cat_mask=cat)
 
@@ -61,7 +59,6 @@ def hierarchical_test_pallas(proj: Projected, grid: TileGrid,
 def entry_cat_mask_pallas(proj: Projected, grid: TileGrid, lists, valid,
                           mode: SamplingMode, prec: PrecisionScheme,
                           spiky_threshold: float = 3.0,
-                          interpret: bool = True,
                           tile_origins=None) -> jax.Array:
     """(T, K, Mt) bool entry CAT mask via the entry-stream PRTU kernel.
 
@@ -81,43 +78,45 @@ def entry_cat_mask_pallas(proj: Projected, grid: TileGrid, lists, valid,
     if tile_origins is None:
         tile_origins = grid.tile_origins()
 
+    # Gather each feature from its own (N,) vector: every (T, K) result is
+    # lane-dense, where gathering (N, 2)/(N, 3) rows would build (T, K, 2)
+    # arrays that a TPU pads 64-fold.
     idx = lists.clip(0)
     lhs = jnp.log(255.0 * jnp.maximum(proj.opacity, 1e-12))[idx]
     lhs = jnp.where(valid & proj.in_frustum[idx], lhs, -jnp.inf)
     spiky = classify_spiky(proj.axis_ratio, spiky_threshold)[idx]
+    feat = prtu.feature_rows(
+        proj.mean2d[:, 0][idx], proj.mean2d[:, 1][idx], proj.conic[:, 0][idx],
+        proj.conic[:, 1][idx], proj.conic[:, 2][idx], lhs, spiky)
     mask = prtu.prtu_entry_cat_mask(
-        p_top_l, p_bot_l, tile_origins, proj.mean2d[idx],
-        proj.conic[idx], lhs, spiky,
+        p_top_l, p_bot_l, tile_origins, feat,
         mode=mode.value, coord_prec=prec.coord, delta_prec=prec.delta,
-        mul_prec=prec.mul, acc_prec=prec.acc, slack=prec.slack,
-        interpret=interpret)
+        mul_prec=prec.mul, acc_prec=prec.acc, slack=prec.slack)
     return mask != 0
 
 
 def entry_cat_fn(mode: SamplingMode, prec: PrecisionScheme,
-                 spiky_threshold: float = 3.0, interpret: bool = True):
+                 spiky_threshold: float = 3.0):
     """The `cat_fn` closure that routes an entry CAT evaluation through the
     Pallas entry-PRTU kernel — the single place the kernel routing lives.
     `core.renderer.RenderPlan.ctu` passes this to
     `hierarchy.stream_entry_test` when `TestConfig.backend == "pallas"`;
     the tile-sharded path calls it with per-shard rows + `tile_origins`."""
     return lambda p, g, ls, v, tile_origins=None: entry_cat_mask_pallas(
-        p, g, ls, v, mode, prec, spiky_threshold, interpret,
-        tile_origins=tile_origins)
+        p, g, ls, v, mode, prec, spiky_threshold, tile_origins=tile_origins)
 
 
 def stream_hierarchical_test_pallas(proj: Projected, grid: TileGrid,
                                     mode: SamplingMode,
                                     prec: PrecisionScheme,
                                     spiky_threshold: float = 3.0, *,
-                                    k_max: int, order=None,
-                                    interpret: bool = True) \
+                                    k_max: int, order=None) \
         -> H.StreamHierarchyOut:
     """`core.hierarchy.stream_hierarchical_test` with the entry CAT routed
     through the Pallas entry-PRTU kernel."""
     return H.stream_hierarchical_test(
         proj, grid, mode, prec, spiky_threshold, k_max=k_max, order=order,
-        cat_fn=entry_cat_fn(mode, prec, spiky_threshold, interpret))
+        cat_fn=entry_cat_fn(mode, prec, spiky_threshold))
 
 
 def gather_tile_features(proj: Projected, grid: TileGrid, lists, valid,
@@ -136,14 +135,14 @@ def gather_tile_features(proj: Projected, grid: TileGrid, lists, valid,
     poffs = raster._pixel_offsets(grid.tile)              # (P, 2)
     pix = t_origins[:, None, :] + poffs[None, :, :]       # (T, P, 2)
 
+    # One (N,) gather per feature (see `entry_cat_mask_pallas`).
     idx = lists.clip(0)
-    feat = jnp.concatenate([
-        proj.mean2d[idx],                                 # (T, K, 2)
-        proj.conic[idx],                                  # (T, K, 3)
-        proj.opacity[idx][..., None],                     # (T, K, 1)
-        jnp.zeros(lists.shape + (2,), jnp.float32),
-    ], axis=-1)
-    colors = proj.color[idx]
+    feat = jnp.stack(
+        [proj.mean2d[:, 0][idx], proj.mean2d[:, 1][idx],
+         proj.conic[:, 0][idx], proj.conic[:, 1][idx], proj.conic[:, 2][idx],
+         proj.opacity[idx], jnp.zeros(lists.shape, jnp.float32),
+         jnp.zeros(lists.shape, jnp.float32)], axis=-1)   # (T, K, 8)
+    colors = jnp.stack([proj.color[:, c][idx] for c in range(3)], axis=-1)
 
     if entry_mask is None:
         allow = jnp.ones(lists.shape + (grid.minitiles_per_tile,), jnp.int8)
@@ -153,10 +152,9 @@ def gather_tile_features(proj: Projected, grid: TileGrid, lists, valid,
     return pix, feat, colors, valid_i8, allow
 
 
-def blend_tiles_pallas(proj, grid, lists, valid, entry_mask=None,
-                       interpret: bool = True):
+def blend_tiles_pallas(proj, grid, lists, valid, entry_mask=None):
     ops = gather_tile_features(proj, grid, lists, valid, entry_mask)
-    return krender.blend_tiles(*ops, interpret=interpret)
+    return krender.blend_tiles(*ops)
 
 
 def blend_tiles_reference(proj, grid, lists, valid, entry_mask=None):
@@ -165,17 +163,16 @@ def blend_tiles_reference(proj, grid, lists, valid, entry_mask=None):
 
 
 def blend_tiles_fused_pallas(proj, grid, lists, valid, entry_mask=None,
-                             init=None, interpret: bool = True,
-                             tile_origins=None) -> krender.FusedBlendOut:
+                             init=None, tile_origins=None
+                             ) -> krender.FusedBlendOut:
     ops = gather_tile_features(proj, grid, lists, valid, entry_mask,
                                tile_origins=tile_origins)
-    return krender.blend_tiles_fused(*ops, init=init, interpret=interpret)
+    return krender.blend_tiles_fused(*ops, init=init)
 
 
 def render_tiles_fused(proj, grid, lists, valid, entry_mask=None,
                        background: float = 0.0,
-                       overflow: jax.Array | bool = False,
-                       interpret: bool = True):
+                       overflow: jax.Array | bool = False):
     """Fused-kernel drop-in for `core.raster.render_tiles` (single pass).
 
     See `render_tiles_fused_passes` for the counters contract and the
@@ -183,13 +180,13 @@ def render_tiles_fused(proj, grid, lists, valid, entry_mask=None,
     """
     return render_tiles_fused_passes(proj, grid,
                                      [(lists, valid, entry_mask)],
-                                     background, overflow, interpret)
+                                     background, overflow)
 
 
 def render_tiles_fused_passes(proj, grid, passes,
                               background: float = 0.0,
-                              overflow: jax.Array | bool = False,
-                              interpret: bool = True, *, span_cb=None):
+                              overflow: jax.Array | bool = False, *,
+                              span_cb=None):
     """Fused-kernel blend over one or more compacted spill passes.
 
     passes: sequence of (lists (T, K), valid, entry_mask) — consecutive
@@ -231,8 +228,7 @@ def render_tiles_fused_passes(proj, grid, passes,
         with (span_cb(i) if span_cb is not None
               else contextlib.nullcontext()):
             fb = blend_tiles_fused_pallas(proj, grid, lists, valid,
-                                          entry_mask, init=state,
-                                          interpret=interpret)
+                                          entry_mask, init=state)
             state = (fb.trans, fb.rgb, fb.processed, fb.blended)
         alive_parts.append(fb.entry_alive)
         kproc = kproc + jnp.sum(fb.kblocks_processed).astype(jnp.float32)

@@ -30,19 +30,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+from repro import kernels
+from repro.core.precision import quantize as _quant
 
 M_BLK = 128   # mini-tiles per block (sublane-friendly)
 G_BLK = 128   # gaussians per block (lane dimension)
-
-
-def _quant(x, kind: str):
-    if kind == "fp16":
-        return x.astype(jnp.float16).astype(jnp.float32)
-    if kind == "fp8":
-        return x.astype(jnp.float8_e4m3fn).astype(jnp.float32)
-    return x
 
 
 def _alg1_hits(ptx, pty, pbx, pby, mu_x, mu_y, cxx, cxy, cyy, lhs, spiky,
@@ -83,54 +77,65 @@ def _alg1_hits(ptx, pty, pbx, pby, mu_x, mu_y, cxx, cxy, cyy, lhs, spiky,
     hit1 = lhs > e1 * k
     hit2 = lhs > e2 * k
     hit3 = lhs > e3 * k
-    dense = hit0 | hit1 | hit2 | hit3
     sparse = hit0 | hit3                 # main diagonal only
+    anti = hit1 | hit2                   # what the dense test adds
 
+    # Mode selection as boolean algebra (sparse ⊆ dense): Mosaic cannot
+    # broadcast a select between bool operands.
     if mode == "uniform_dense":
-        return dense
+        return sparse | anti
     if mode == "uniform_sparse":
         return sparse
     if mode == "smooth_focused":
-        return jnp.where(spiky, sparse, dense)
+        return sparse | (anti & ~spiky)
     if mode == "spiky_focused":
-        return jnp.where(spiky, dense, sparse)
+        return sparse | (anti & spiky)
     raise ValueError(mode)
 
 
-def _prtu_kernel(ptop_ref, pbot_ref, mu_ref, conic_ref, lhs_ref, spiky_ref,
-                 mask_ref, *, mode: str, coord_prec: str, delta_prec: str,
-                 mul_prec: str, acc_prec: str, slack: float):
+def _prtu_kernel(ptop_ref, pbot_ref, feat_ref, mask_ref, *, mode: str,
+                 coord_prec: str, delta_prec: str, mul_prec: str,
+                 acc_prec: str, slack: float):
     """One (M_BLK, G_BLK) block of the CAT test matrix.
 
     ptop/pbot: (M_BLK, 2) — main-diagonal leader coords of each mini-tile PR.
-    mu: (G_BLK, 2), conic: (G_BLK, 3), lhs: (G_BLK,) = ln(255·o) (shared term,
-    computed once outside, as in the CTU), spiky: (G_BLK,) int8.
-    mask: (M_BLK, G_BLK) int8 out.
+    feat: (8, G_BLK) per-Gaussian rows [mu_x, mu_y, cxx, cxy, cyy, lhs,
+    spiky, 0] with lhs = ln(255·o) (shared term, computed once outside, as
+    in the CTU) and spiky as 0/1. mask: (M_BLK, G_BLK) int8 out.
     """
     qc = functools.partial(_quant, kind=coord_prec)
     out = _alg1_hits(
-        ptx=qc(ptop_ref[:, 0][:, None]),         # (M, 1)
-        pty=qc(ptop_ref[:, 1][:, None]),
-        pbx=qc(pbot_ref[:, 0][:, None]),
-        pby=qc(pbot_ref[:, 1][:, None]),
-        mu_x=qc(mu_ref[:, 0][None, :]),          # (1, G)
-        mu_y=qc(mu_ref[:, 1][None, :]),
-        cxx=qc(conic_ref[:, 0][None, :]),
-        cxy=qc(conic_ref[:, 1][None, :]),
-        cyy=qc(conic_ref[:, 2][None, :]),
-        lhs=lhs_ref[:][None, :],
-        spiky=spiky_ref[:][None, :] != 0,
+        ptx=qc(ptop_ref[:, 0:1]),                # (M, 1)
+        pty=qc(ptop_ref[:, 1:2]),
+        pbx=qc(pbot_ref[:, 0:1]),
+        pby=qc(pbot_ref[:, 1:2]),
+        mu_x=qc(feat_ref[0:1, :]),               # (1, G)
+        mu_y=qc(feat_ref[1:2, :]),
+        cxx=qc(feat_ref[2:3, :]),
+        cxy=qc(feat_ref[3:4, :]),
+        cyy=qc(feat_ref[4:5, :]),
+        lhs=feat_ref[5:6, :],
+        spiky=feat_ref[6:7, :] != 0,
         mode=mode, delta_prec=delta_prec, mul_prec=mul_prec,
         acc_prec=acc_prec, slack=slack)
     mask_ref[...] = out.astype(jnp.int8)
+
+
+def feature_rows(mu_x, mu_y, cxx, cxy, cyy, lhs, spiky):
+    """Stack per-Gaussian (or per-entry) operands as the 8 PRTU feature
+    rows [mu_x, mu_y, cxx, cxy, cyy, lhs, spiky, 0] along axis -2: shape
+    (..., 8, L). Gaussians (entries) run along the last, lane axis, so the
+    operand needs no lane padding on a TPU."""
+    vals = [mu_x, mu_y, cxx, cxy, cyy, lhs, spiky]
+    vals = [v.astype(jnp.float32) for v in vals]
+    return jnp.stack(vals + [jnp.zeros_like(vals[0])], axis=-2)
 
 
 def prtu_cat_mask(p_top: jax.Array, p_bot: jax.Array, mu: jax.Array,
                   conic: jax.Array, lhs: jax.Array, spiky: jax.Array,
                   *, mode: str = "smooth_focused", coord_prec: str = "fp16",
                   delta_prec: str = "fp8", mul_prec: str = "fp8",
-                  acc_prec: str = "fp16", slack: float = 0.0,
-                  interpret: bool = True) -> jax.Array:
+                  acc_prec: str = "fp16", slack: float = 0.0) -> jax.Array:
     """(M, G) int8 CAT mask via the Pallas PRTU kernel.
 
     Pads M and G up to block multiples; callers slice the result.
@@ -139,19 +144,15 @@ def prtu_cat_mask(p_top: jax.Array, p_bot: jax.Array, mu: jax.Array,
     mp = -(-m // M_BLK) * M_BLK
     gp = -(-g // G_BLK) * G_BLK
 
-    def pad(x, n, axis=0):
-        w = [(0, 0)] * x.ndim
-        w[axis] = (0, n - x.shape[axis])
-        return jnp.pad(x, w)
+    def pad(x, n):
+        return jnp.pad(x, [(0, n - x.shape[0])] + [(0, 0)] * (x.ndim - 1))
 
-    p_top_p = pad(p_top.astype(jnp.float32), mp)
-    p_bot_p = pad(p_bot.astype(jnp.float32), mp)
-    mu_p = pad(mu.astype(jnp.float32), gp)
-    conic_p = pad(conic.astype(jnp.float32), gp)
-    # padded lhs = -inf so padded Gaussians never pass
+    # Padded Gaussians carry lhs = -inf so they never pass.
     lhs_p = jnp.full((gp,), -jnp.inf, jnp.float32).at[:g].set(
         lhs.astype(jnp.float32))
-    spiky_p = pad(spiky.astype(jnp.int8), gp)
+    mu_p, conic_p = pad(mu, gp), pad(conic, gp)
+    feat = feature_rows(mu_p[:, 0], mu_p[:, 1], conic_p[:, 0], conic_p[:, 1],
+                        conic_p[:, 2], lhs_p, pad(spiky, gp))    # (8, Gp)
 
     kernel = functools.partial(_prtu_kernel, mode=mode,
                                coord_prec=coord_prec, delta_prec=delta_prec,
@@ -163,20 +164,18 @@ def prtu_cat_mask(p_top: jax.Array, p_bot: jax.Array, mu: jax.Array,
         in_specs=[
             pl.BlockSpec((M_BLK, 2), lambda i, j: (i, 0)),
             pl.BlockSpec((M_BLK, 2), lambda i, j: (i, 0)),
-            pl.BlockSpec((G_BLK, 2), lambda i, j: (j, 0)),
-            pl.BlockSpec((G_BLK, 3), lambda i, j: (j, 0)),
-            pl.BlockSpec((G_BLK,), lambda i, j: (j,)),
-            pl.BlockSpec((G_BLK,), lambda i, j: (j,)),
+            pl.BlockSpec((8, G_BLK), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((M_BLK, G_BLK), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, gp), jnp.int8),
         # Unlike the blend kernels there is no carried state: every
         # (mini-tile, Gaussian) block is independent, so both grid axes are
         # parallel and Mosaic may reorder/overlap them freely.
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
-        interpret=interpret,
-    )(p_top_p, p_bot_p, mu_p, conic_p, lhs_p, spiky_p)
+        interpret=kernels.interpret_mode(),
+    )(pad(p_top.astype(jnp.float32), mp), pad(p_bot.astype(jnp.float32), mp),
+      feat)
     return out[:m, :g]
 
 
@@ -187,68 +186,61 @@ def prtu_cat_mask(p_top: jax.Array, p_bot: jax.Array, mu: jax.Array,
 KE_BLK = 128  # stream entries per block (lane dimension)
 
 
-def _prtu_entry_kernel(ptop_ref, pbot_ref, orig_ref, mu_ref, conic_ref,
-                       lhs_ref, spiky_ref, mask_ref, *, mode: str,
-                       coord_prec: str, delta_prec: str, mul_prec: str,
-                       acc_prec: str, slack: float):
+def _prtu_entry_kernel(ptop_ref, pbot_ref, orig_ref, feat_ref, mask_ref, *,
+                       mode: str, coord_prec: str, delta_prec: str,
+                       mul_prec: str, acc_prec: str, slack: float):
     """One (1 tile, KE_BLK entries) block of the survivor-stream CAT test.
 
     ptop/pbot: (Mt, 2) tile-LOCAL main-diagonal leader coords of the tile's
-    mini-tile PRs (shared by every tile); orig: (1, 2) this tile's pixel
-    origin. mu: (1, KE, 2), conic: (1, KE, 3), lhs: (1, KE) = ln(255·o)
-    with -inf on invalid/padded entries, spiky: (1, KE) int8.
-    mask: (1, KE, Mt) int8 out — entry k of this tile vs mini-tile m.
+    mini-tile PRs (shared by every tile); orig: (1, 1, 2) this tile's pixel
+    origin. feat: (1, 8, KE) per-entry `feature_rows`, lhs = ln(255·o) with
+    -inf on invalid/padded entries.
+    mask: (1, Mt, KE) int8 out — mini-tile m of this tile vs entry k.
     """
     qc = functools.partial(_quant, kind=coord_prec)
-    ox = orig_ref[0, 0]
-    oy = orig_ref[0, 1]
+    orig = orig_ref[0]                           # (1, 2)
+    ox = orig[:, 0:1]                            # (1, 1)
+    oy = orig[:, 1:2]
+    feat = feat_ref[0]                           # (8, KE)
     out = _alg1_hits(
-        ptx=qc(ox + ptop_ref[:, 0][None, :]),    # (1, Mt)
-        pty=qc(oy + ptop_ref[:, 1][None, :]),
-        pbx=qc(ox + pbot_ref[:, 0][None, :]),
-        pby=qc(oy + pbot_ref[:, 1][None, :]),
-        mu_x=qc(mu_ref[0, :, 0][:, None]),       # (KE, 1)
-        mu_y=qc(mu_ref[0, :, 1][:, None]),
-        cxx=qc(conic_ref[0, :, 0][:, None]),
-        cxy=qc(conic_ref[0, :, 1][:, None]),
-        cyy=qc(conic_ref[0, :, 2][:, None]),
-        lhs=lhs_ref[0][:, None],                 # (KE, 1)
-        spiky=spiky_ref[0][:, None] != 0,
+        ptx=qc(ox + ptop_ref[:, 0:1]),           # (Mt, 1)
+        pty=qc(oy + ptop_ref[:, 1:2]),
+        pbx=qc(ox + pbot_ref[:, 0:1]),
+        pby=qc(oy + pbot_ref[:, 1:2]),
+        mu_x=qc(feat[0:1, :]),                   # (1, KE)
+        mu_y=qc(feat[1:2, :]),
+        cxx=qc(feat[2:3, :]),
+        cxy=qc(feat[3:4, :]),
+        cyy=qc(feat[4:5, :]),
+        lhs=feat[5:6, :],
+        spiky=feat[6:7, :] != 0,
         mode=mode, delta_prec=delta_prec, mul_prec=mul_prec,
         acc_prec=acc_prec, slack=slack)
-    mask_ref[0] = out.astype(jnp.int8)           # (KE, Mt)
+    mask_ref[0] = out.astype(jnp.int8)           # (Mt, KE)
 
 
 def prtu_entry_cat_mask(p_top_local: jax.Array, p_bot_local: jax.Array,
-                        tile_origins: jax.Array, mu: jax.Array,
-                        conic: jax.Array, lhs: jax.Array, spiky: jax.Array,
+                        tile_origins: jax.Array, feat: jax.Array,
                         *, mode: str = "smooth_focused",
                         coord_prec: str = "fp16", delta_prec: str = "fp8",
                         mul_prec: str = "fp8", acc_prec: str = "fp16",
-                        slack: float = 0.0,
-                        interpret: bool = True) -> jax.Array:
+                        slack: float = 0.0) -> jax.Array:
     """(T, K, Mt) int8 CAT mask over compacted list entries.
 
     p_top_local/p_bot_local: (Mt, 2) tile-local leader coords; tile_origins:
-    (T, 2); mu/conic/lhs/spiky: per-entry features gathered at the compacted
-    lists, shapes (T, K, 2)/(T, K, 3)/(T, K)/(T, K). Invalid entries must
-    carry lhs = -inf (they then never pass). K is padded to a KE_BLK
-    multiple internally; callers get the unpadded slice back.
+    (T, 2); feat: (T, 8, K) per-entry `feature_rows` gathered at the
+    compacted lists. Invalid entries must carry lhs = -inf (they then never
+    pass). K is padded to a KE_BLK multiple internally; callers get the
+    unpadded slice back.
     """
-    t, k = lhs.shape
+    t, _, k = feat.shape
     mt = p_top_local.shape[0]
     kpad = -(-k // KE_BLK) * KE_BLK
-
-    def padk(x):
-        w = [(0, 0)] * x.ndim
-        w[1] = (0, kpad - k)
-        return jnp.pad(x, w)
-
-    mu_p = padk(mu.astype(jnp.float32))
-    conic_p = padk(conic.astype(jnp.float32))
-    lhs_p = jnp.pad(lhs.astype(jnp.float32), ((0, 0), (0, kpad - k)),
-                    constant_values=-jnp.inf)
-    spiky_p = padk(spiky.astype(jnp.int8))
+    # Padded entries: lhs = -inf (row 5), so they never pass.
+    pad_rows = jnp.zeros((8, 1), jnp.float32).at[5].set(-jnp.inf)
+    feat = jnp.concatenate(
+        [feat.astype(jnp.float32),
+         jnp.broadcast_to(pad_rows, (t, 8, kpad - k))], axis=2)
 
     kernel = functools.partial(_prtu_entry_kernel, mode=mode,
                                coord_prec=coord_prec, delta_prec=delta_prec,
@@ -260,19 +252,17 @@ def prtu_entry_cat_mask(p_top_local: jax.Array, p_bot_local: jax.Array,
         in_specs=[
             pl.BlockSpec((mt, 2), lambda i, j: (0, 0)),
             pl.BlockSpec((mt, 2), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, 2), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, KE_BLK, 2), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, KE_BLK, 3), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, KE_BLK), lambda i, j: (i, j)),
-            pl.BlockSpec((1, KE_BLK), lambda i, j: (i, j)),
+            # Per-tile origin as (T, 1, 2): the blocked tile axis leads.
+            pl.BlockSpec((1, 1, 2), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, 8, KE_BLK), lambda i, j: (i, 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, KE_BLK, mt), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((t, kpad, mt), jnp.int8),
+        out_specs=pl.BlockSpec((1, mt, KE_BLK), lambda i, j: (i, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((t, mt, kpad), jnp.int8),
         # Every (tile, entry-block) is independent — no carried state, both
         # grid axes parallel, same as the dense PRTU kernel.
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(p_top_local.astype(jnp.float32), p_bot_local.astype(jnp.float32),
-      tile_origins.astype(jnp.float32), mu_p, conic_p, lhs_p, spiky_p)
-    return out[:, :k, :]
+      tile_origins.astype(jnp.float32).reshape(t, 1, 2), feat)
+    return jnp.swapaxes(out[:, :, :k], 1, 2)

@@ -17,7 +17,7 @@ def _allow_pixels(allow, p: int):
     """(T, K, Mt) i8 per-entry mask -> (T, P, K) bool per-pixel lanes.
 
     Oracle-side counterpart of the kernels' in-VMEM one-hot expansion
-    (`render._expand_allow`), sharing its pixel→mini-tile derivation."""
+    (in `render._blend_block`), sharing its pixel→mini-tile derivation."""
     mt_in_tile = pixel_minitile_index(p, allow.shape[2])       # (P,)
     return allow[:, :, mt_in_tile].swapaxes(1, 2) != 0         # (T, P, K)
 

@@ -43,6 +43,8 @@ FIFOs in Fig. 6):
                        (the survivor-stream representation — 16× smaller
                        than a per-pixel mask; `StreamHierarchyOut
                        .entry_mini_mask`)
+The wrappers hand them to the kernels lane-dense, long axis last
+(`_kernel_operands`; docs/kernels.md "Kernel-side layout").
 The kernels expand the per-entry mask to pixel lanes in VMEM with a one-hot
 (P, Mt) pixel→mini-tile matmul (static per grid; matmul rather than gather
 so the expansion lowers to the MXU instead of an unsupported dynamic
@@ -63,24 +65,75 @@ from repro.core.gaussians import ALPHA_MIN
 from repro.core.raster import T_EPS  # transmittance floor: all pixel lanes
 #                                      below => tile terminated; shared with
 #                                      the jnp rasterizer's modeled counters
-from repro.kernels.compat import CompilerParams
+from repro import kernels
 
 ALPHA_MAX = 0.99
 
 K_BLK = 128
 
-
-def _expand_allow(allow, mtmap):
-    """(K, Mt) i8 per-entry mask -> (P, K) bool pixel-lane mask.
-
-    mtmap: (P, Mt) f32 one-hot pixel→mini-tile map. Each row has exactly one
-    1, so the matmul reproduces the gather exactly (values stay 0/1)."""
-    return (mtmap @ allow.astype(jnp.float32).T) > 0.5
+# Per-tile pixel state, (T, STATE_ROWS, P): one row per quantity so the
+# pixel axis is the lane axis. Rows: transmittance, r, g, b, processed,
+# blended, K blocks executed (fused kernel only), unused.
+STATE_ROWS = 8
 
 
-def _blend_kernel(pix_ref, feat_ref, col_ref, valid_ref, allow_ref,
-                  mtmap_ref, rgb_ref, trans_ref, t_scr, acc_scr,
-                  *, n_kblocks: int):
+def _prefix_product(x):
+    """Inclusive product scan along the lane axis of a (P, K) block.
+
+    Log-step doubling over static shifts (Hillis-Steele): Mosaic has no
+    cumprod lowering, and this stays a handful of VPU multiplies."""
+    s = 1
+    while s < x.shape[1]:
+        x = x * jnp.concatenate([jnp.ones_like(x[:, :s]), x[:, :-s]], axis=1)
+        s *= 2
+    return x
+
+
+def _blend_block(pix_ref, feat_ref, col_ref, allow_ref, mtmap_ref, t_in):
+    """Blend one (P pixels × K_BLK entries) block front to back.
+
+    Blocks arrive lane-dense: pix (1, 2, P), feat (1, 8, K) rows [mean_x,
+    mean_y, cxx, cxy, cyy, opacity, valid, 0], col (1, 3, K), allow
+    (1, Mt, K) i8; mtmap (P, Mt) is the one-hot pixel→mini-tile map.
+    t_in: (P, 1) transmittance carried into the block. Returns (lane (P, K)
+    bool, a (P, K) alpha, t_excl (P, K) transmittance entering each entry,
+    rgb (P, 3) the block's colour contribution, t_out (P, 1))."""
+    pix = pix_ref[0].T                     # (P, 2)
+    px = pix[:, 0:1]                       # (P, 1)
+    py = pix[:, 1:2]
+    feat = feat_ref[0]                     # (8, K)
+    mx, my, cxx, cxy, cyy, op, valid = (feat[j:j + 1, :] for j in range(7))
+
+    dx = px - mx                           # (P, K)
+    dy = py - my
+    e = 0.5 * (cxx * dx * dx + cyy * dy * dy) + cxy * dx * dy
+    a = jnp.minimum(op * jnp.exp(-e), ALPHA_MAX)
+    # Per-entry mask -> pixel lanes: each mtmap row has exactly one 1, so
+    # the matmul reproduces the gather exactly (values stay 0/1) and lowers
+    # to the MXU instead of an unsupported dynamic gather.
+    allow = jnp.dot(mtmap_ref[...], allow_ref[0].astype(jnp.float32)) > 0.5
+    lane = (valid != 0) & allow
+    a = jnp.where(lane & (a >= ALPHA_MIN), a, 0.0)
+
+    # Sequential front-to-back blend within the block via a prefix product.
+    cum = _prefix_product(1.0 - a)
+    t_excl = t_in * jnp.concatenate(
+        [jnp.ones_like(cum[:, :1]), cum[:, :-1]], axis=1)
+    rgb = jax.lax.dot_general(             # (P, K) x (3, K) -> (P, 3)
+        t_excl * a, col_ref[0], (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST)
+    return lane, a, t_excl, rgb, t_in * cum[:, -1:]
+
+
+def _store_state(st_ref, t_scr, acc_scr):
+    """Write the carried (P, 1) transmittance and (P, 3) colour columns to
+    rows 0-3 of the (1, STATE_ROWS, P) state block."""
+    st_ref[0, 0:1, :] = t_scr[...].T
+    st_ref[0, 1:4, :] = acc_scr[...].T
+
+
+def _blend_kernel(pix_ref, feat_ref, col_ref, allow_ref, mtmap_ref, st_ref,
+                  t_scr, acc_scr, *, n_kblocks: int):
     k = pl.program_id(1)
 
     @pl.when(k == 0)
@@ -88,42 +141,15 @@ def _blend_kernel(pix_ref, feat_ref, col_ref, valid_ref, allow_ref,
         t_scr[...] = jnp.ones_like(t_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    pix = pix_ref[0]                       # (P, 2)
-    feat = feat_ref[0]                     # (K, 8)
-    col = col_ref[0]                       # (K, 3)
-    valid = valid_ref[0]                   # (K,)
-    allow = allow_ref[0]                   # (K, Mt) per-entry mask
-
-    px = pix[:, 0][:, None]                # (P, 1)
-    py = pix[:, 1][:, None]
-    mx = feat[:, 0][None, :]               # (1, K)
-    my = feat[:, 1][None, :]
-    cxx = feat[:, 2][None, :]
-    cxy = feat[:, 3][None, :]
-    cyy = feat[:, 4][None, :]
-    op = feat[:, 5][None, :]
-
-    dx = px - mx                           # (P, K)
-    dy = py - my
-    e = 0.5 * (cxx * dx * dx + cyy * dy * dy) + cxy * dx * dy
-    a = jnp.minimum(op * jnp.exp(-e), ALPHA_MAX)
-    allow_pk = _expand_allow(allow, mtmap_ref[...])          # (P, K)
-    ok = (valid[None, :] != 0) & allow_pk & (a >= ALPHA_MIN)
-    a = jnp.where(ok, a, 0.0)              # (P, K)
-
-    # Sequential front-to-back blend within the block via cumprod.
-    cum = jnp.cumprod(1.0 - a, axis=1)
-    t_in = t_scr[...][:, None]             # (P, 1) carried transmittance
-    t_excl = t_in * jnp.concatenate(
-        [jnp.ones_like(cum[:, :1]), cum[:, :-1]], axis=1)
-    w = t_excl * a                         # (P, K)
-    acc_scr[...] += w @ col                # (P, 3)
-    t_scr[...] *= cum[:, -1]
+    _, _, _, rgb, t_out = _blend_block(pix_ref, feat_ref, col_ref, allow_ref,
+                                       mtmap_ref, t_scr[...])
+    acc_scr[...] += rgb
+    t_scr[...] = t_out
 
     @pl.when(k == n_kblocks - 1)
     def _out():
-        rgb_ref[0] = acc_scr[...]
-        trans_ref[0] = t_scr[...]
+        st_ref[...] = jnp.zeros_like(st_ref)
+        _store_state(st_ref, t_scr, acc_scr)
 
 
 def pixel_minitile_index(p: int, mt: int) -> jnp.ndarray:
@@ -146,56 +172,72 @@ def _pixel_minitile_onehot(p: int, mt: int) -> jnp.ndarray:
         jnp.float32)
 
 
+def _kernel_operands(pix, feat, colors, valid, allow):
+    """Pad K to a K_BLK multiple and lay the operands out lane-dense.
+
+    Every operand keeps its long axis (pixels or list entries) last and its
+    short one (coordinates, features, channels, mini-tiles) second to last:
+    the TPU tiles the last two dims of an array by (8, 128), so a (T, K, 8)
+    layout would pad each 8-wide row to 128 lanes in HBM (16×; 4 GB per
+    Full-HD frame and spill pass). `valid` rides in feature row 6."""
+    t, k = valid.shape
+    kp = -(-k // K_BLK) * K_BLK
+
+    def rows(x):                            # (T, K, C) -> (T, C, Kp)
+        return jnp.pad(jnp.swapaxes(x, 1, 2), ((0, 0), (0, 0), (0, kp - k)))
+
+    feat_rows = jnp.stack(
+        [feat[..., j].astype(jnp.float32) for j in range(6)]
+        + [valid.astype(jnp.float32), jnp.zeros((t, k), jnp.float32)],
+        axis=-1)
+    return (jnp.swapaxes(pix.astype(jnp.float32), 1, 2),
+            rows(feat_rows),
+            rows(colors.astype(jnp.float32)),
+            rows(allow.astype(jnp.int8))), kp
+
+
+def _operand_specs(p: int, mt: int):
+    """BlockSpecs of (pix, feat, colors, allow, mtmap) for grid step
+    (tile i, K block j)."""
+    return [
+        pl.BlockSpec((1, 2, p), lambda i, j, *_: (i, 0, 0)),
+        pl.BlockSpec((1, 8, K_BLK), lambda i, j, *_: (i, 0, j)),
+        pl.BlockSpec((1, 3, K_BLK), lambda i, j, *_: (i, 0, j)),
+        pl.BlockSpec((1, mt, K_BLK), lambda i, j, *_: (i, 0, j)),
+        pl.BlockSpec((p, mt), lambda i, j, *_: (0, 0)),
+    ]
+
+
+def _state_spec(p: int):
+    return pl.BlockSpec((1, STATE_ROWS, p), lambda i, j, *_: (i, 0, 0))
+
+
 def blend_tiles(pix: jax.Array, feat: jax.Array, colors: jax.Array,
-                valid: jax.Array, allow: jax.Array,
-                interpret: bool = True):
+                valid: jax.Array, allow: jax.Array):
     """pix: (T, P, 2); feat: (T, K, 8); colors: (T, K, 3); valid: (T, K) i8;
     allow: (T, K, Mt) i8 per-entry mask over the tile's mini-tiles.
     Returns (rgb (T, P, 3), transmittance (T, P))."""
     t, p, _ = pix.shape
-    k = feat.shape[1]
     mt = allow.shape[2]
-    kp = -(-k // K_BLK) * K_BLK
-    if kp != k:
-        padk = kp - k
-        feat = jnp.pad(feat, ((0, 0), (0, padk), (0, 0)))
-        colors = jnp.pad(colors, ((0, 0), (0, padk), (0, 0)))
-        valid = jnp.pad(valid, ((0, 0), (0, padk)))
-        allow = jnp.pad(allow, ((0, 0), (0, padk), (0, 0)))
+    ops, kp = _kernel_operands(pix, feat, colors, valid, allow)
     n_kblocks = kp // K_BLK
-    mtmap = _pixel_minitile_onehot(p, mt)
 
     kernel = functools.partial(_blend_kernel, n_kblocks=n_kblocks)
-    rgb, trans = pl.pallas_call(
+    state = pl.pallas_call(
         kernel,
         grid=(t, n_kblocks),
-        in_specs=[
-            pl.BlockSpec((1, p, 2), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, K_BLK, 8), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, K_BLK, 3), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, K_BLK), lambda i, j: (i, j)),
-            pl.BlockSpec((1, K_BLK, mt), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((p, mt), lambda i, j: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, p, 3), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, p), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((t, p, 3), jnp.float32),
-            jax.ShapeDtypeStruct((t, p), jnp.float32),
-        ],
+        in_specs=_operand_specs(p, mt),
+        out_specs=_state_spec(p),
+        out_shape=jax.ShapeDtypeStruct((t, STATE_ROWS, p), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((p,), jnp.float32),
+            pltpu.VMEM((p, 1), jnp.float32),
             pltpu.VMEM((p, 3), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=interpret,
-    )(pix.astype(jnp.float32), feat.astype(jnp.float32),
-      colors.astype(jnp.float32), valid.astype(jnp.int8),
-      allow.astype(jnp.int8), mtmap)
-    return rgb, trans
+        interpret=kernels.interpret_mode(),
+    )(*ops, _pixel_minitile_onehot(p, mt))
+    return jnp.swapaxes(state[:, 1:4], 1, 2), state[:, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -213,11 +255,10 @@ class FusedBlendOut(NamedTuple):
     kblocks_total: int            # static: K blocks a full sweep would run
 
 
-def _fused_blend_kernel(kb_ref, pix_ref, feat_ref, col_ref, valid_ref,
-                        allow_ref, mtmap_ref, t0_ref, acc0_ref, p0_ref,
-                        b0_ref, rgb_ref, trans_ref, proc_ref,
-                        blnd_ref, alive_ref, kproc_ref, t_scr, acc_scr,
-                        pcnt_scr, bcnt_scr, kp_scr, *, n_kblocks: int):
+def _fused_blend_kernel(kb_ref, pix_ref, feat_ref, col_ref, allow_ref,
+                        mtmap_ref, st0_ref, st_ref, alive_ref, t_scr,
+                        acc_scr, pcnt_scr, bcnt_scr, kp_scr, *,
+                        n_kblocks: int):
     i = pl.program_id(0)
     k = pl.program_id(1)
 
@@ -225,12 +266,13 @@ def _fused_blend_kernel(kb_ref, pix_ref, feat_ref, col_ref, valid_ref,
     # zero accumulators on the first pass) — the cross-call analogue of the
     # cross-K-block carry the scratch already implements, which is what
     # makes a spill pass resume exactly where the previous one stopped.
+    # State rows arrive as (1, P) and live in scratch as (P, 1) columns.
     @pl.when(k == 0)
     def _init():
-        t_scr[...] = t0_ref[0]
-        acc_scr[...] = acc0_ref[0]
-        pcnt_scr[...] = p0_ref[0]
-        bcnt_scr[...] = b0_ref[0]
+        t_scr[...] = st0_ref[0, 0:1, :].T
+        acc_scr[...] = st0_ref[0, 1:4, :].T
+        pcnt_scr[...] = st0_ref[0, 4:5, :].T
+        bcnt_scr[...] = st0_ref[0, 5:6, :].T
         kp_scr[0] = 0
 
     # Skipped blocks (terminated tile or past the tile's occupied bound)
@@ -240,66 +282,40 @@ def _fused_blend_kernel(kb_ref, pix_ref, feat_ref, col_ref, valid_ref,
     # The fused decision: run this block only while (a) the compacted list
     # still has entries here and (b) some pixel lane is above the
     # transmittance floor. Both guards skip the block's whole dataflow.
-    active = (k < kb_ref[i]) & jnp.any(t_scr[...] >= T_EPS)
+    active = (k < kb_ref[i]) & (jnp.max(t_scr[...]) >= T_EPS)
 
     @pl.when(active)
     def _blend():
-        pix = pix_ref[0]                   # (P, 2)
-        feat = feat_ref[0]                 # (K, 8)
-        col = col_ref[0]                   # (K, 3)
-        valid = valid_ref[0]               # (K,)
-        allow = allow_ref[0]               # (K, Mt) per-entry mask
-
-        px = pix[:, 0][:, None]            # (P, 1)
-        py = pix[:, 1][:, None]
-        mx = feat[:, 0][None, :]           # (1, K)
-        my = feat[:, 1][None, :]
-        cxx = feat[:, 2][None, :]
-        cxy = feat[:, 3][None, :]
-        cyy = feat[:, 4][None, :]
-        op = feat[:, 5][None, :]
-
-        dx = px - mx                       # (P, K)
-        dy = py - my
-        e = 0.5 * (cxx * dx * dx + cyy * dy * dy) + cxy * dx * dy
-        a = jnp.minimum(op * jnp.exp(-e), ALPHA_MAX)
-        allow_pk = _expand_allow(allow, mtmap_ref[...])
-        lane = (valid[None, :] != 0) & allow_pk         # (P, K)
-        a = jnp.where(lane & (a >= ALPHA_MIN), a, 0.0)
-
-        cum = jnp.cumprod(1.0 - a, axis=1)
-        t_in = t_scr[...][:, None]         # (P, 1) carried transmittance
-        t_excl = t_in * jnp.concatenate(
-            [jnp.ones_like(cum[:, :1]), cum[:, :-1]], axis=1)
-        w = t_excl * a                     # (P, K)
-        acc_scr[...] += w @ col
-        t_scr[...] *= cum[:, -1]
+        lane, a, t_excl, rgb, t_out = _blend_block(
+            pix_ref, feat_ref, col_ref, allow_ref, mtmap_ref, t_scr[...])
+        acc_scr[...] += rgb
+        t_scr[...] = t_out
 
         # Measured work — same accounting as core.raster.render_tiles, but
         # produced by the kernel that did the work.
         alive_px = t_excl >= T_EPS         # (P, K)
         pcnt_scr[...] += jnp.sum((lane & alive_px).astype(jnp.float32),
-                                 axis=1)
+                                 axis=1, keepdims=True)
         bcnt_scr[...] += jnp.sum(((a > 0) & alive_px).astype(jnp.float32),
-                                 axis=1)
-        alive_ref[0] = (jnp.any(alive_px, axis=0)
-                        & (valid != 0)).astype(jnp.int8)
+                                 axis=1, keepdims=True)
+        alive_ref[0] = (jnp.any(alive_px, axis=0, keepdims=True)
+                        & (feat_ref[0, 6:7, :] != 0)).astype(jnp.int8)
         kp_scr[0] += 1
 
     @pl.when(k == n_kblocks - 1)
     def _out():
-        rgb_ref[0] = acc_scr[...]
-        trans_ref[0] = t_scr[...]
-        proc_ref[0] = pcnt_scr[...]
-        blnd_ref[0] = bcnt_scr[...]
-        kproc_ref[0, 0] = kp_scr[0]
+        _store_state(st_ref, t_scr, acc_scr)
+        st_ref[0, 4:5, :] = pcnt_scr[...].T
+        st_ref[0, 5:6, :] = bcnt_scr[...].T
+        st_ref[0, 6:7, :] = jnp.full((1, st_ref.shape[2]),
+                                     kp_scr[0].astype(jnp.float32))
+        st_ref[0, 7:8, :] = jnp.zeros((1, st_ref.shape[2]), jnp.float32)
 
 
 def blend_tiles_fused(pix: jax.Array, feat: jax.Array, colors: jax.Array,
                       valid: jax.Array, allow: jax.Array,
                       kblock_bound: Optional[jax.Array] = None,
-                      init: Optional[tuple] = None,
-                      interpret: bool = True) -> FusedBlendOut:
+                      init: Optional[tuple] = None) -> FusedBlendOut:
     """Contribution-aware blend with in-kernel early termination.
 
     Same operands as `blend_tiles`. `kblock_bound` is the optional (T,) i32
@@ -320,15 +336,8 @@ def blend_tiles_fused(pix: jax.Array, feat: jax.Array, colors: jax.Array,
     t, p, _ = pix.shape
     k = feat.shape[1]
     mt = allow.shape[2]
-    kp = -(-k // K_BLK) * K_BLK
-    if kp != k:
-        padk = kp - k
-        feat = jnp.pad(feat, ((0, 0), (0, padk), (0, 0)))
-        colors = jnp.pad(colors, ((0, 0), (0, padk), (0, 0)))
-        valid = jnp.pad(valid, ((0, 0), (0, padk)))
-        allow = jnp.pad(allow, ((0, 0), (0, padk), (0, 0)))
+    ops, kp = _kernel_operands(pix, feat, colors, valid, allow)
     n_kblocks = kp // K_BLK
-    mtmap = _pixel_minitile_onehot(p, mt)
 
     if kblock_bound is None:
         # Compacted lists put valid entries first, so the occupied-block
@@ -338,67 +347,48 @@ def blend_tiles_fused(pix: jax.Array, feat: jax.Array, colors: jax.Array,
     kblock_bound = kblock_bound.astype(jnp.int32)
 
     if init is None:
-        t0 = jnp.ones((t, p), jnp.float32)
-        acc0 = jnp.zeros((t, p, 3), jnp.float32)
-        p0 = jnp.zeros((t, p), jnp.float32)
-        b0 = jnp.zeros((t, p), jnp.float32)
+        state0 = jnp.zeros((t, STATE_ROWS, p), jnp.float32).at[:, 0].set(1.0)
     else:
-        t0, acc0, p0, b0 = (x.astype(jnp.float32) for x in init)
         # A fully-terminated or fully-empty spill pass still runs its
         # guarded grid (the scalar bound already skips dead blocks).
+        t0, acc0, p0, b0 = (x.astype(jnp.float32) for x in init)
+        state0 = jnp.concatenate(
+            [t0[:, None], jnp.swapaxes(acc0, 1, 2), p0[:, None],
+             b0[:, None], jnp.zeros((t, STATE_ROWS - 6, p), jnp.float32)],
+            axis=1)
 
     kernel = functools.partial(_fused_blend_kernel, n_kblocks=n_kblocks)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(t, n_kblocks),
-        in_specs=[
-            pl.BlockSpec((1, p, 2), lambda i, j, kb: (i, 0, 0)),
-            pl.BlockSpec((1, K_BLK, 8), lambda i, j, kb: (i, j, 0)),
-            pl.BlockSpec((1, K_BLK, 3), lambda i, j, kb: (i, j, 0)),
-            pl.BlockSpec((1, K_BLK), lambda i, j, kb: (i, j)),
-            pl.BlockSpec((1, K_BLK, mt), lambda i, j, kb: (i, j, 0)),
-            pl.BlockSpec((p, mt), lambda i, j, kb: (0, 0)),
-            pl.BlockSpec((1, p), lambda i, j, kb: (i, 0)),
-            pl.BlockSpec((1, p, 3), lambda i, j, kb: (i, 0, 0)),
-            pl.BlockSpec((1, p), lambda i, j, kb: (i, 0)),
-            pl.BlockSpec((1, p), lambda i, j, kb: (i, 0)),
-        ],
+        in_specs=_operand_specs(p, mt) + [_state_spec(p)],
         out_specs=[
-            pl.BlockSpec((1, p, 3), lambda i, j, kb: (i, 0, 0)),
-            pl.BlockSpec((1, p), lambda i, j, kb: (i, 0)),
-            pl.BlockSpec((1, p), lambda i, j, kb: (i, 0)),
-            pl.BlockSpec((1, p), lambda i, j, kb: (i, 0)),
-            pl.BlockSpec((1, K_BLK), lambda i, j, kb: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j, kb: (i, 0)),
+            _state_spec(p),
+            pl.BlockSpec((1, 1, K_BLK), lambda i, j, kb: (i, 0, j)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((p,), jnp.float32),      # transmittance carry
+            pltpu.VMEM((p, 1), jnp.float32),    # transmittance carry
             pltpu.VMEM((p, 3), jnp.float32),    # rgb accumulator
-            pltpu.VMEM((p,), jnp.float32),      # processed counter
-            pltpu.VMEM((p,), jnp.float32),      # blended counter
+            pltpu.VMEM((p, 1), jnp.float32),    # processed counter
+            pltpu.VMEM((p, 1), jnp.float32),    # blended counter
             pltpu.SMEM((1,), jnp.int32),        # executed-block counter
         ],
     )
-    rgb, trans, proc, blnd, alive, kproc = pl.pallas_call(
+    state, alive = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((t, p, 3), jnp.float32),
-            jax.ShapeDtypeStruct((t, p), jnp.float32),
-            jax.ShapeDtypeStruct((t, p), jnp.float32),
-            jax.ShapeDtypeStruct((t, p), jnp.float32),
-            jax.ShapeDtypeStruct((t, kp), jnp.int8),
-            jax.ShapeDtypeStruct((t, 1), jnp.int32),
+            jax.ShapeDtypeStruct((t, STATE_ROWS, p), jnp.float32),
+            jax.ShapeDtypeStruct((t, 1, kp), jnp.int8),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=interpret,
-    )(kblock_bound, pix.astype(jnp.float32), feat.astype(jnp.float32),
-      colors.astype(jnp.float32), valid.astype(jnp.int8),
-      allow.astype(jnp.int8), mtmap, t0, acc0, p0, b0)
+        interpret=kernels.interpret_mode(),
+    )(kblock_bound, *ops, _pixel_minitile_onehot(p, mt), state0)
     return FusedBlendOut(
-        rgb=rgb, trans=trans, processed=proc, blended=blnd,
-        entry_alive=(alive[:, :k] != 0),
-        kblocks_processed=kproc[:, 0],
+        rgb=jnp.swapaxes(state[:, 1:4], 1, 2), trans=state[:, 0],
+        processed=state[:, 4], blended=state[:, 5],
+        entry_alive=(alive[:, 0, :k] != 0),
+        kblocks_processed=state[:, 6, 0].astype(jnp.int32),
         kblocks_total=n_kblocks,
     )

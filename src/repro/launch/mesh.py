@@ -10,15 +10,15 @@ state (dryrun must set XLA_FLAGS before the first jax call).
 """
 from __future__ import annotations
 
-import jax
+from repro.distributed.sharding import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh():
     """1-device mesh with the production axis names (smoke tests)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))

@@ -21,6 +21,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 
 
@@ -124,6 +125,7 @@ def serve_lm(args) -> int:
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", default="render", choices=["render", "lm"])
     # render

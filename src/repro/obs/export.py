@@ -13,16 +13,14 @@ Three output shapes, one source of truth each:
   registry's scrape-format dump (`MetricsRegistry.expose` does the real
   work; this module only adds the file plumbing).
 
-Plus `jax_profiler_trace`, a guarded pass-through to `jax.profiler.trace`
-for real-device runs: on TPU/GPU it captures an XLA-level profile alongside
-the host-side span tree; where the profiler is unavailable it degrades to a
-no-op with a warning instead of failing the render.
+Plus `jax_profiler_trace`, a switchable `jax.profiler.trace` for
+real-device runs: it captures an XLA-level profile alongside the host-side
+span tree, and raises where the profiler cannot start.
 """
 from __future__ import annotations
 
 import contextlib
 import json
-import warnings
 from typing import Iterable, Sequence, Union
 
 from repro.obs.trace import NoopTracer, Span, Tracer
@@ -125,22 +123,12 @@ def write_metrics(registry: MetricsRegistry, path) -> None:
 
 @contextlib.contextmanager
 def jax_profiler_trace(logdir, enabled: bool = True):
-    """Pass-through to `jax.profiler.trace(logdir)` that degrades to a
-    no-op (with a warning) where the profiler cannot start — so the same
-    tracing entry points work on CPU CI and real devices."""
+    """`jax.profiler.trace(logdir)` when `enabled`, else a no-op. A profiler
+    that cannot start raises: a run asked to trace never silently
+    produces no profile."""
     if not enabled:
         yield
         return
     import jax
-    try:
-        cm = jax.profiler.trace(str(logdir))
-        cm.__enter__()
-    except Exception as exc:                      # pragma: no cover - env
-        warnings.warn(f"jax.profiler.trace unavailable ({exc!r}); "
-                      "continuing without a device profile")
+    with jax.profiler.trace(str(logdir)):
         yield
-        return
-    try:
-        yield
-    finally:
-        cm.__exit__(None, None, None)

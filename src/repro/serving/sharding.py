@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.distributed.sharding import dp_axes, resolve
+from repro.distributed.sharding import dp_axes, make_mesh, resolve
 
 
 def data_parallel_size(mesh: Mesh) -> int:
@@ -54,7 +54,7 @@ def tile_mesh(tile_shards: int, frame_shards: int = 1) -> Mesh:
             f"({frame_shards} frame x {tile_shards} tile) but only {avail} "
             "are visible; set XLA_FLAGS="
             "--xla_force_host_platform_device_count=N")
-    return jax.make_mesh((frame_shards, tile_shards), ("data", "model"))
+    return make_mesh((frame_shards, tile_shards), ("data", "model"))
 
 
 def shard_frames(batch, mesh: Mesh):

@@ -17,7 +17,7 @@ import jax
 import numpy as np
 
 from repro.core import OverflowPolicy, RenderPlan, StreamConfig, \
-    orbit_camera, random_scene
+    TestConfig, orbit_camera, random_scene
 from repro.serving.engine import RenderEngine
 
 DEMO_SCENE_KW = dict(scale_range=(-2.9, -2.4), stretch=4.0,
@@ -242,11 +242,15 @@ def register_hd1080_scene(engine: RenderEngine,
 def hd1080_engine(n_gaussians: int = HD1080_GAUSSIANS, *,
                   k_max_pass: int = 512,
                   max_spill_passes: int = 8,
-                  fused: Optional[bool] = None) -> tuple[RenderEngine, str]:
+                  fused: Optional[bool] = None,
+                  backend: str = "jnp",
+                  **engine_kw) -> tuple[RenderEngine, str]:
     """The 1080p serving configuration in one call: a SPILL-policy engine
     (per-pass chunk `k_max_pass`, pass bucket derived per scene at render
     time) with the frame-size-aware batching policy, and the 512k-Gaussian
     HD scene registered under 'hd1080'. Returns (engine, scene_name).
+    `backend` picks the CTU ("jnp" or the "pallas" PRTU kernel); other
+    keywords (e.g. `shard_tiles`) go to `RenderEngine`.
 
     SPILL is what makes this workload servable: Full-HD survivor lists
     exceed any memory-comfortable single k_max, so overflow entries render
@@ -255,11 +259,12 @@ def hd1080_engine(n_gaussians: int = HD1080_GAUSSIANS, *,
     default; the engine re-derives the real pass bucket from the scene's
     measured survivor bound.
     """
-    base = RenderPlan(stream=StreamConfig(
-        k_max=k_max_pass, overflow=OverflowPolicy.SPILL,
-        max_spill_passes=max_spill_passes))
+    base = RenderPlan(
+        test=TestConfig(backend=backend),
+        stream=StreamConfig(k_max=k_max_pass, overflow=OverflowPolicy.SPILL,
+                            max_spill_passes=max_spill_passes))
     engine = RenderEngine(
         base, fused=fused,
-        max_batch=max_batch_for(HD1080_HEIGHT, HD1080_WIDTH))
+        max_batch=max_batch_for(HD1080_HEIGHT, HD1080_WIDTH), **engine_kw)
     name = register_hd1080_scene(engine, n_gaussians)
     return engine, name

@@ -118,3 +118,30 @@ def test_subtile_aabb_nearly_superset_of_exact(proj64, grid64):
     missed = jnp.sum(oracle & ~sub[sub_of_mini])
     total = jnp.maximum(jnp.sum(oracle), 1)
     assert float(missed / total) < 0.005
+
+
+@pytest.mark.parametrize("jit", [False, True], ids=["eager", "jit"])
+@pytest.mark.parametrize("kind", ["fp16", "fp8"])
+def test_quantize_equals_cast_round_trip(kind, jit):
+    """`quantize` is bit-identical to the fp16 / float8_e4m3fn cast round
+    trip (as ml_dtypes computes it on the host), across magnitudes,
+    subnormals, ties, overflow and arbitrary bit patterns."""
+    import ml_dtypes
+    from repro.core.precision import quantize
+    rng = np.random.default_rng(0)
+    x = np.concatenate(
+        [rng.standard_normal(20000).astype(np.float32) * s
+         for s in (1e-9, 1e-4, 1e-2, 1, 300, 7e4)]
+        + [rng.integers(0, 2**32, 50000, dtype=np.uint64)
+           .astype(np.uint32).view(np.float32),
+           np.array([0., -0., 448, 464, -464, 470, 480, 65504, 65520, 2**-6,
+                     2**-9, 2**-10, 1.5 * 2**-10, 2**-14, 2**-24, 2**-25,
+                     np.inf, -np.inf, np.nan], np.float32)])
+    dt = {"fp16": np.float16, "fp8": ml_dtypes.float8_e4m3fn}[kind]
+    with np.errstate(over="ignore", invalid="ignore"):
+        ref = x.astype(dt).astype(np.float32)
+    f = (lambda v: quantize(v, kind))
+    got = np.asarray((jax.jit(f) if jit else f)(jnp.asarray(x)))
+    same = ((got.view(np.uint32) == ref.view(np.uint32))
+            | (np.isnan(got) & np.isnan(ref)))
+    assert same.all(), x[~same][:8]

@@ -210,3 +210,29 @@ def test_stream_renders_where_dense_mask_would_not_fit():
     assert img.max() > 0.01               # actually rendered something
     assert float(counters["cat_mask_bytes"]) == float(stream_bytes)
     assert float(counters["vru_pairs"]) > 0
+
+
+@pytest.mark.parametrize("mode", list(SamplingMode))
+def test_stream_ctu_prs_exact_past_float32_integers(mode, proj64, grid64):
+    """`ctu_prs` is an exact integer sum: past 2^24 a float32 sum would
+    round in whatever order the compiler reduces, and the tile-sharded and
+    single-device programs reduce in different orders."""
+    from repro.core.hierarchy import stream_entry_counters
+    from repro.core.gaussians import classify_spiky
+    rng = np.random.default_rng(0)
+    shape = (4096, 2048)
+    lists = jnp.asarray(rng.integers(0, proj64.depth.shape[0], shape),
+                        jnp.int32)
+    sub_hits = jnp.asarray(rng.integers(0, 17, shape), jnp.int32)
+    valid = jnp.ones(shape, bool)
+    got = jax.jit(stream_entry_counters, static_argnums=(1, 6))(
+        proj64, grid64, lists, valid, sub_hits, sub_hits, mode)["ctu_prs"]
+    spiky = np.asarray(classify_spiky(proj64.axis_ratio))[np.asarray(lists)]
+    prs2 = {SamplingMode.UNIFORM_DENSE: np.full(shape, 2),
+            SamplingMode.UNIFORM_SPARSE: np.full(shape, 1),
+            SamplingMode.SMOOTH_FOCUSED: np.where(spiky, 1, 2),
+            SamplingMode.SPIKY_FOCUSED: np.where(spiky, 2, 1)}[mode]
+    total2 = int(np.sum(np.asarray(sub_hits, np.int64) * prs2)) \
+        * grid64.minitiles_per_subtile
+    assert total2 > 2 * 2**24       # ctu_prs past float32's exact integers
+    assert float(got) == float(np.float32(total2) / 2)

@@ -1,7 +1,10 @@
 """End-to-end behaviour tests: the paper's full pipeline (train a scene,
 prune, render with FLICKER) and training/serving drivers."""
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
+import pytest
 
 from repro.core.gaussians import random_scene, project
 from repro.core.camera import default_camera
@@ -100,14 +103,51 @@ def test_train_driver_with_compression(tmp_path):
     assert rc == 0
 
 
-def test_serve_driver_render():
+@pytest.fixture
+def cache_dir(monkeypatch, tmp_path):
+    """Point the persistent compilation cache (the serve driver turns it
+    on) at a temporary directory, and restore this process's setting
+    afterwards so no later test compiles into it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    path = tmp_path / "jax_cache"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(path))
+    was = jax.config.jax_compilation_cache_dir
+    yield path
+    jax.config.update("jax_compilation_cache_dir", was)
+    compilation_cache.reset_cache()
+
+
+def test_compile_cache_lands_in_env_dir(cache_dir):
+    from jax.experimental.compilation_cache import compilation_cache
+    from repro.launch.compile_cache import enable_compile_cache
+    assert enable_compile_cache() == str(cache_dir)
+    compilation_cache.reset_cache()
+    was = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    try:
+        jax.jit(lambda x: jnp.sin(x) * 3.0 + 1.0)(jnp.ones(7))
+    finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", was)
+    assert any(cache_dir.iterdir())
+
+
+def test_compile_cache_defaults_to_checkout(cache_dir, monkeypatch):
+    from repro.launch import compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = compile_cache.enable_compile_cache()
+    checkout = Path(__file__).resolve().parents[1]
+    assert path == str(checkout / ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == path
+
+
+def test_serve_driver_render(cache_dir):
     from repro.launch.serve import main
     rc = main(["--mode", "render", "--frames", "2", "--res", "32",
                "--gaussians", "200"])
     assert rc == 0
 
 
-def test_serve_driver_lm():
+def test_serve_driver_lm(cache_dir):
     from repro.launch.serve import main
     rc = main(["--mode", "lm", "--arch", "zamba2-1.2b", "--reduced",
                "--batch", "1", "--prefill", "32", "--decode", "3"])
